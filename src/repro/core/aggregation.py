@@ -25,9 +25,12 @@ def _mix_leaf(w: jnp.ndarray, leaf: jnp.ndarray) -> jnp.ndarray:
     """(k,m) x (m, ...) -> (k, ...) in the leaf's dtype.
 
     Inputs stay in the leaf dtype (so any collective the mix lowers to moves
-    bf16, not fp32); the contraction accumulates in fp32."""
+    bf16, not fp32); the contraction accumulates in fp32.  HIGHEST keeps
+    float32 leaves and weights in float32 on a TPU, whose default rounds
+    matmul inputs to bf16 (the weights would no longer sum to one)."""
     out = jax.lax.dot_general(
         w.astype(leaf.dtype), leaf, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     return out.astype(leaf.dtype)
 

@@ -15,30 +15,15 @@ sharded over `axis`; inside shard_map each shard holds m/axis_size clients.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax>=0.4.35 exposes shard_map at jax.shard_map
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod.shard_map if hasattr(_shard_map_mod, "shard_map") \
-        else _shard_map_mod
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# the replication-check kwarg was renamed check_rep -> check_vma in newer jax
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(shard_map).parameters else "check_rep")
-_NO_CHECK = {_CHECK_KW: False}
-
-
-def _leaf_specs(params: Any, inner_spec_fn) -> Any:
-    return jax.tree_util.tree_map(lambda l: inner_spec_fn(l), params)
+# every mix contracts float32 weights and params at full precision: a TPU
+# otherwise rounds matmul inputs to bf16 (`aggregation._mix_leaf`)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def mix_unicast_shard_map(mesh, axis: str, params: Any, w: jnp.ndarray) -> Any:
@@ -57,13 +42,14 @@ def mix_unicast_shard_map(mesh, axis: str, params: Any, w: jnp.ndarray) -> Any:
         w_rows = jax.lax.dynamic_slice_in_dim(w_rep, idx * mm, mm, 0)  # (mm, m)
         return jax.tree_util.tree_map(
             lambda g: jnp.tensordot(w_rows.astype(jnp.float32),
-                                    g.astype(jnp.float32),
-                                    axes=(1, 0)).astype(g.dtype), gathered)
+                                    g.astype(jnp.float32), axes=(1, 0),
+                                    precision=HIGHEST).astype(g.dtype),
+            gathered)
 
     pspec = jax.tree_util.tree_map(
         lambda l: P(axis, *([None] * (l.ndim - 1))), params)
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), pspec),
-                   out_specs=pspec, **_NO_CHECK)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), pspec),
+                       out_specs=pspec, check_vma=False)
     return fn(w, params)
 
 
@@ -83,7 +69,8 @@ def mix_streams_shard_map(mesh, axis: str, params: Any,
         w_cols = jax.lax.dynamic_slice_in_dim(w_rep, idx * mm, mm, 1)  # (k, mm)
         contrib = jax.tree_util.tree_map(
             lambda l: jnp.tensordot(w_cols.astype(jnp.float32),
-                                    l.astype(jnp.float32), axes=(1, 0)),
+                                    l.astype(jnp.float32), axes=(1, 0),
+                                    precision=HIGHEST),
             p_local)                                            # (k, ...)
         mixed = jax.lax.psum(contrib, axis)                     # all shards: (k, ...)
         my_assign = jax.lax.dynamic_slice_in_dim(assign, idx * mm, mm, 0)
@@ -93,8 +80,8 @@ def mix_streams_shard_map(mesh, axis: str, params: Any,
 
     pspec = jax.tree_util.tree_map(
         lambda l: P(axis, *([None] * (l.ndim - 1))), params)
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P(), pspec),
-                   out_specs=pspec, **_NO_CHECK)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), pspec),
+                       out_specs=pspec, check_vma=False)
     return fn(centroids, assignment, params)
 
 
@@ -131,6 +118,7 @@ def mix_einsum(params: Any, w: jnp.ndarray, assignment=None) -> Any:
     def leaf(l):
         out = jax.lax.dot_general(w.astype(l.dtype), l,
                                   (((1,), (0,)), ((), ())),
+                                  precision=HIGHEST,
                                   preferred_element_type=jnp.float32)
         return out.astype(l.dtype)
     mixed = jax.tree_util.tree_map(leaf, params)

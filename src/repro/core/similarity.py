@@ -38,11 +38,13 @@ def client_gradients(loss_fn: Callable, params, datasets: Sequence) -> jnp.ndarr
 def delta_matrix(grads: jnp.ndarray) -> jnp.ndarray:
     """Δ_{i,j} = ||g_i - g_j||² from stacked gradients (m, D).
 
-    Computed via the Gram matrix (one pass over D): ||g_i||² + ||g_j||² − 2⟨g_i,g_j⟩.
+    Computed via the Gram matrix (one pass over D): ||g_i||² + ||g_j||² − 2⟨g_i,g_j⟩,
+    in full float32 — the difference cancels, so the bf16 inputs of a TPU
+    matmul at default precision would swamp it.
     """
     g = grads.astype(jnp.float32)
     sq = jnp.sum(g * g, axis=-1)
-    gram = g @ g.T
+    gram = jnp.matmul(g, g.T, precision=jax.lax.Precision.HIGHEST)
     d = sq[:, None] + sq[None, :] - 2.0 * gram
     return jnp.maximum(d, 0.0)
 

@@ -7,6 +7,7 @@ guides the choice of m_t, per the paper.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -19,9 +20,15 @@ class StreamPlan(NamedTuple):
     inertia: jnp.ndarray       # scalar, final k-means objective
 
 
+# the k-means algebra runs in full float32: a TPU matmul at default
+# precision rounds its inputs to bf16, which the distance cancellation
+# below and the row-stochastic centroids cannot absorb
+_mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
 def _pairwise_sq(a, b):
     return (jnp.sum(a * a, 1)[:, None] + jnp.sum(b * b, 1)[None, :]
-            - 2.0 * a @ b.T)
+            - 2.0 * _mm(a, b.T))
 
 
 def kmeans(rows: jnp.ndarray, k: int, *, n_iter: int = 50,
@@ -62,7 +69,7 @@ def kmeans(rows: jnp.ndarray, k: int, *, n_iter: int = 50,
         assign = jnp.argmin(d, axis=1)
         oh = jax.nn.one_hot(assign, k, dtype=jnp.float32)  # (m, k)
         counts = jnp.maximum(jnp.sum(oh, axis=0), 1.0)
-        new = (oh.T @ x) / counts[:, None]
+        new = _mm(oh.T, x) / counts[:, None]
         # keep empty clusters where they were
         new = jnp.where((jnp.sum(oh, axis=0) > 0)[:, None], new, cents)
         return new, None
@@ -75,7 +82,7 @@ def kmeans(rows: jnp.ndarray, k: int, *, n_iter: int = 50,
     # to remain aggregation rules (row-stochastic)
     oh = jax.nn.one_hot(assign, k, dtype=jnp.float32)
     counts = jnp.maximum(jnp.sum(oh, axis=0), 1.0)
-    cents = (oh.T @ raw) / counts[:, None]
+    cents = _mm(oh.T, raw) / counts[:, None]
     cents = cents / jnp.maximum(jnp.sum(cents, axis=1, keepdims=True), 1e-9)
     return StreamPlan(cents, assign, inertia)
 
@@ -88,7 +95,7 @@ def silhouette_score(rows: jnp.ndarray, assignment: jnp.ndarray,
     d = jnp.sqrt(jnp.maximum(_pairwise_sq(x, x), 0.0))        # (m, m)
     oh = jax.nn.one_hot(assignment, k, dtype=jnp.float32)     # (m, k)
     counts = jnp.sum(oh, axis=0)                              # (k,)
-    sums = d @ oh                                             # (m, k)
+    sums = _mm(d, oh)                                         # (m, k)
     own = counts[assignment]
     a = jnp.where(own > 1,
                   jnp.take_along_axis(sums, assignment[:, None], 1)[:, 0]

@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container kernels run with interpret=True (the TPU lowering is
-the target; interpret executes the same kernel body).  `INTERPRET` flips
-automatically off when a TPU backend is present.
+The TPU lowering is the target.  Each wrapper decides its mode when it is
+called, from the default backend: compiled on a TPU, interpreted on the
+CPU (the same kernel body, executed by the Pallas interpreter), and an
+error on any other platform.  Importing this module initialises no
+backend.
 """
 from __future__ import annotations
 
@@ -21,7 +23,17 @@ from repro.kernels.quantize import (qsgd_dequantize as _qsgd_deq,
                                     rowwise_absmax as _absmax)
 from repro.kernels.topk_threshold import topk_threshold as _topk
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Pallas mode for the backend the wrapper is called on."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels compile for a TPU and are "
+                       f"interpreted on the CPU; backend {platform!r} has "
+                       f"neither path")
 
 
 def _pad_rows(a: jnp.ndarray, mult: int = 8):
@@ -36,20 +48,20 @@ def mixing_aggregate(w: jnp.ndarray, theta: jnp.ndarray, *,
     pk, pm = (-k) % 8, (-m) % 8
     w2 = jnp.pad(w, ((0, pk), (0, pm)))
     theta2 = jnp.pad(theta, ((0, pm), (0, 0)))
-    out = _mix(w2, theta2, dblk=dblk, interpret=INTERPRET)
+    out = _mix(w2, theta2, dblk=dblk, interpret=_interpret())
     return out[:k]
 
 
 def pairwise_sqdist(g: jnp.ndarray, *, dblk: int = 2048) -> jnp.ndarray:
     m = g.shape[0]
     g2, _ = _pad_rows(g)
-    return _sqdist(g2, dblk=dblk, interpret=INTERPRET)[:m, :m]
+    return _sqdist(g2, dblk=dblk, interpret=_interpret())[:m, :m]
 
 
 def gram_matrix(g: jnp.ndarray, *, dblk: int = 2048) -> jnp.ndarray:
     m = g.shape[0]
     g2, _ = _pad_rows(g)
-    return _gram(g2, dblk=dblk, interpret=INTERPRET)[:m, :m]
+    return _gram(g2, dblk=dblk, interpret=_interpret())[:m, :m]
 
 
 def qsgd_quantize(x: jnp.ndarray, noise: jnp.ndarray, *, bits: int,
@@ -59,8 +71,9 @@ def qsgd_quantize(x: jnp.ndarray, noise: jnp.ndarray, *, bits: int,
     m = x.shape[0]
     x2, _ = _pad_rows(x)
     noise2, _ = _pad_rows(noise)
-    amax = _absmax(x2, dblk=dblk, interpret=INTERPRET)
-    q = _qsgd_q(x2, noise2, amax, bits=bits, dblk=dblk, interpret=INTERPRET)
+    interpret = _interpret()
+    amax = _absmax(x2, dblk=dblk, interpret=interpret)
+    q = _qsgd_q(x2, noise2, amax, bits=bits, dblk=dblk, interpret=interpret)
     return q[:m], amax[:m]
 
 
@@ -70,7 +83,7 @@ def qsgd_dequantize(q: jnp.ndarray, absmax: jnp.ndarray, *, bits: int,
     q2, _ = _pad_rows(q)
     amax2, _ = _pad_rows(absmax)
     return _qsgd_deq(q2, amax2, bits=bits, dblk=dblk,
-                     interpret=INTERPRET)[:m]
+                     interpret=_interpret())[:m]
 
 
 def qsgd_roundtrip(x: jnp.ndarray, noise: jnp.ndarray, *, bits: int,
@@ -85,7 +98,7 @@ def topk_threshold(absx: jnp.ndarray, *, k: int, rblk: int = 8
     """Per-row top-k magnitude cutoff (m, 1); rows padded to rblk."""
     m = absx.shape[0]
     absx2, _ = _pad_rows(absx, mult=rblk)
-    return _topk(absx2, k=k, rblk=rblk, interpret=INTERPRET)[:m]
+    return _topk(absx2, k=k, rblk=rblk, interpret=_interpret())[:m]
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -93,9 +106,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     softcap: Optional[float] = None,
                     qblk: int = 128, kblk: int = 128) -> jnp.ndarray:
     return _flash(q, k, v, causal=causal, window=window, softcap=softcap,
-                  qblk=qblk, kblk=kblk, interpret=INTERPRET)
+                  qblk=qblk, kblk=kblk, interpret=_interpret())
 
 
 __all__ = ["mixing_aggregate", "pairwise_sqdist", "gram_matrix",
            "flash_attention", "qsgd_quantize", "qsgd_dequantize",
-           "qsgd_roundtrip", "topk_threshold", "ref", "INTERPRET"]
+           "qsgd_roundtrip", "topk_threshold", "ref"]

@@ -30,6 +30,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.models import scan as scan_mod
 from repro.models import transformer as T
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import init_model_params, _use_scan
 
 
@@ -70,6 +71,7 @@ def main(argv=None):
     p.add_argument("--max-batch", type=int, default=4,
                    help="federated: micro-batcher chunk size")
     args = p.parse_args(argv)
+    enable_compile_cache()
     if args.federated:
         return federated_main(args)
     return smoke_main(args)

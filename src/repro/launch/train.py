@@ -39,6 +39,7 @@ from repro.data.synthetic import synthetic_lm_tokens
 from repro.fl import (AsyncConfig, Channel, FLConfig, HierarchyConfig,
                       HostVmap, MeshShardMap, PagingConfig, SYSTEMS,
                       UniformFraction, get_strategy, run_federated)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import _loss_fn, init_model_params
 
 
@@ -262,6 +263,7 @@ def main(argv=None):
                         " reschedule delay")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
     if args.steps < 1:
         p.error("--steps must be >= 1")
     _validate_specs(p, args)

@@ -81,7 +81,10 @@ def _segsum(dA):
     diff = cs[..., :, None] - cs[..., None, :]                 # (..., h, c, c)
     c = dA.shape[-2]
     mask = jnp.tril(jnp.ones((c, c), bool))
-    return jnp.where(mask, jnp.exp(diff), 0.0)
+    # mask before exp: above the diagonal diff is a positive sum that
+    # overflows to inf over a long chunk, and where()'s gradient would
+    # then multiply that inf by zero
+    return jnp.exp(jnp.where(mask, diff, -jnp.inf))
 
 
 def ssd_scan(x, dt, A, B, C, chunk: int,

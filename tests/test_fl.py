@@ -73,6 +73,28 @@ def test_ucfl_mixing_matrix_recorded():
     np.testing.assert_allclose(w.sum(1), np.ones(6), rtol=1e-4)
 
 
+@pytest.mark.parametrize("stat", ["full_client_gradients",
+                                  "sigma2_estimates"])
+def test_client_stats_chunked_match_whole_stack(stat, monkeypatch):
+    """Taking the clients a chunk at a time (2 per chunk, 5 clients: two
+    chunks and a remainder) gives each client the statistic it gets when
+    the whole stack goes through at once, up to float32 reassociation of
+    the sums over a client's samples."""
+    from repro.fl import stats
+    from repro.models import lenet
+    fed = _tiny_fed(m=5)
+    params = lenet.init_params(KEY, lenet.LeNetConfig())
+    args = (lenet.loss_fn, params, fed) + (
+        (3,) if stat == "sigma2_estimates" else ())
+    fn = getattr(stats, stat).__wrapped__     # traced anew on each call
+    whole = np.asarray(fn(*args))
+    monkeypatch.setattr(stats, "CHUNK_SAMPLES", 2 * fed.x.shape[1])
+    chunked = np.asarray(fn(*args))
+    assert chunked.shape == whole.shape and whole.shape[0] == 5
+    bound = fed.x.shape[1] * np.finfo(np.float32).eps * np.abs(whole).max()
+    np.testing.assert_allclose(chunked, whole, rtol=0, atol=bound)
+
+
 def test_training_improves_over_init():
     fed = _tiny_fed(m=4, n=500)
     fl = FLConfig(rounds=8, local_steps=5, batch_size=32, eval_every=7)
