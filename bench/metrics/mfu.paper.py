@@ -1,0 +1,12 @@
+"""mfu.paper: LeNet training FLOPs of the rounds completed in the window
+(3 x forward, from the shapes, `bench/configs/lenet-paper.py`) over the
+window's length times the chips' bf16 peak.  Moves round_s."""
+from bench import harness
+
+
+def read(ctx):
+    cell = ctx["cell"]
+    flops = cell.model.train_flops_per_round(cell.config, cell.mix)
+    peak = harness.peak(ctx["device"]["kind"], "bf16_flops_per_s")
+    return 100.0 * flops * ctx["rounds"] / (ctx["window_s"] * peak
+                                            * cell.chips)

@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU, with the repository root and
+`src/` importable."""
+import os
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
