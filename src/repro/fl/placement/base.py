@@ -159,8 +159,9 @@ class Placement(abc.ABC):
         chunk's eval costs no extra program dispatch.  Same vmapped math
         as the eventful `evaluate`; the (mean, worst) reduction stays
         host-side (`reduce_scores`) on both paths so they cannot drift."""
-        return jax.vmap(lambda p, x, y: acc_fn(p, {"x": x, "y": y}))(
-            stacked, x_val, y_val)
+        with jax.named_scope("eval"):
+            return jax.vmap(lambda p, x, y: acc_fn(p, {"x": x, "y": y}))(
+                stacked, x_val, y_val)
 
     def stage(self, tree: Any, m: int) -> Any:
         """Begin the host->device transfer of a gathered cohort pytree

@@ -31,14 +31,19 @@ def make_client_update(loss_fn: Callable, opt, fl):
         n_slots = x_i.shape[0]
 
         def step(carry, k):
+            # named scopes (DESIGN.md §3h): the backward pass, remat's
+            # recompute included, carries `transpose(` inside `loss`
             p, o = carry
-            idx = jax.random.randint(k, (fl.batch_size,), 0, 1 << 30) % \
-                jnp.maximum(n_i.astype(jnp.int32), 1)
-            idx = idx % n_slots
-            batch = {"x": x_i[idx], "y": y_i[idx]}
-            grads, _ = jax.grad(loss_fn, has_aux=True)(p, batch)
-            upd, o = opt.update(grads, o, p)
-            return (apply_updates(p, upd), o), None
+            with jax.named_scope("local_update/batch"):
+                idx = jax.random.randint(k, (fl.batch_size,), 0, 1 << 30)
+                idx = idx % jnp.maximum(n_i.astype(jnp.int32), 1) % n_slots
+                batch = {"x": x_i[idx], "y": y_i[idx]}
+            with jax.named_scope("local_update/loss"):
+                grads, _ = jax.grad(loss_fn, has_aux=True)(p, batch)
+            with jax.named_scope("local_update/optimizer"):
+                upd, o = opt.update(grads, o, p)
+                p = apply_updates(p, upd)
+            return (p, o), None
 
         keys = jax.random.split(key, fl.local_steps)
         # NOTE: do not be tempted to unroll this scan — unrolling lets XLA

@@ -126,13 +126,16 @@ def init_run(strategy: Strategy, fed: FederatedData, fl: "FLConfig",
     key, kinit = jax.random.split(key)
     if model_init is None:
         model_init = default_model_init(fed)
-    params0 = model_init(kinit)
+    with jax.profiler.TraceAnnotation("fl.model_init"):
+        params0 = model_init(kinit)
     if hierarchy is None:
         opt, vmapped_update = placement.build_update(loss_fn, fl,
                                                      donate=donate)
-        stacked = placement.stack(params0, m)
-        opt_state = placement.init_opt(opt, stacked)
-        data = placement.place_data(fed)
+        with jax.profiler.TraceAnnotation("fl.stack"):
+            stacked = placement.stack(params0, m)
+            opt_state = placement.init_opt(opt, stacked)
+        with jax.profiler.TraceAnnotation("fl.place_data"):
+            data = placement.place_data(fed)
         plan = None
     else:
         from repro.fl.hierarchy import init_fleet_run
@@ -145,7 +148,8 @@ def init_run(strategy: Strategy, fed: FederatedData, fl: "FLConfig",
                        strategy=strategy)
     ctx.hierarchy_plan = plan
     ctx.fault_plan = resolve_fault_plan(faults, m)
-    state = strategy.setup(ctx)
+    with jax.profiler.TraceAnnotation("fl.strategy_setup"):
+        state = strategy.setup(ctx)
     return key, vmapped_update, stacked, opt_state, data, ctx, state
 
 
@@ -400,7 +404,8 @@ def _build_traced_round(strategy: Strategy, sampler: Optional[ClientSampler],
         if defense is not None:
             stacked, q = screen_and_defend(defense, stacked, prev)
             tmix.quarantine = q
-        stacked = strategy.aggregate_traced(consts, stacked, prev, tmix)
+        with jax.named_scope("aggregate"):
+            stacked = strategy.aggregate_traced(consts, stacked, prev, tmix)
         tmix.quarantine = None
         if min_quorum is not None:
             count = (jnp.float32(m) if part is None
@@ -547,98 +552,106 @@ def _run_superstep(strategy: Strategy, fed: FederatedData, *,
     scan; their per-round crash/quarantine rows ride the superstep outs
     next to the masks and are replayed into the `FaultMeter` here."""
     m = fed.m
-    key, update_fn, stacked, opt_state, data, ctx, state = init_run(
-        strategy, fed, fl, model_init, loss_fn, acc_fn, placement, seed,
-        donate=False,   # donation happens at the superstep boundary instead
-        hierarchy=hierarchy, system=system, faults=faults)
-    plan = ctx.fault_plan
-    defense = get_robust_aggregator(robust_agg)
-    robust_spec = "none" if defense is None else str(robust_agg)
-    meter = None
-    if hierarchy is not None:
-        from repro.fl.hierarchy import EdgeMeter
-        meter = EdgeMeter(ctx.hierarchy_plan)
-    fmeter = None
-    if plan is not None or defense is not None or min_quorum is not None:
-        fmeter = FaultMeter(plan, robust_spec, min_quorum)
-    payload, link, model_bits, ef, channel = init_channel(
-        channel, ctx, stacked, system, m)
-    lossy = channel is not None and not channel.codec.is_identity
-    # identity codecs trace no uplink: normalize so channel-less and
-    # identity-channel runs share one compiled superstep
-    codec = channel.codec if lossy else None
-    ef_flag = channel.error_feedback if lossy else True
-    consts = strategy.traced_state(state)
-    if plan is not None:
-        # the static adversary row rides as a traced const input (§3g)
-        consts = (consts, jnp.asarray(plan.byz_row()))
-    round_fn = _build_traced_round(strategy, sampler, codec, ef_flag,
-                                   placement, update_fn, fault_plan=plan,
-                                   defense=defense, min_quorum=min_quorum)
-    cache = _superstep_cache(placement, strategy, sampler, codec, ef_flag,
-                             update_fn, acc_fn,
-                             fault_cfg=None if plan is None else plan.cfg,
-                             robust_spec=robust_spec, min_quorum=min_quorum)
-    eval_fn = lambda st, ed: placement.eval_traced(acc_fn, st, ed[0], ed[1])
-    cost = strategy.comm(state)     # round-constant by the traceability
-    history = History()             # contract (state never changes)
-    assignment = strategy.membership(state)      # round-constant too
-    ul_bits_pc = per_client_uplink_bits(channel, ctx, payload, m)
-    t_accum = 0.0
-    carry = (key, stacked, opt_state, ef if lossy else None)
+    with jax.profiler.TraceAnnotation("fl.init"):
+        key, update_fn, stacked, opt_state, data, ctx, state = init_run(
+            strategy, fed, fl, model_init, loss_fn, acc_fn, placement, seed,
+            # donation happens at the superstep boundary instead
+            donate=False, hierarchy=hierarchy, system=system, faults=faults)
+        plan = ctx.fault_plan
+        defense = get_robust_aggregator(robust_agg)
+        robust_spec = "none" if defense is None else str(robust_agg)
+        meter = None
+        if hierarchy is not None:
+            from repro.fl.hierarchy import EdgeMeter
+            meter = EdgeMeter(ctx.hierarchy_plan)
+        fmeter = None
+        if plan is not None or defense is not None or min_quorum is not None:
+            fmeter = FaultMeter(plan, robust_spec, min_quorum)
+        payload, link, model_bits, ef, channel = init_channel(
+            channel, ctx, stacked, system, m)
+        lossy = channel is not None and not channel.codec.is_identity
+        # identity codecs trace no uplink: normalize so channel-less and
+        # identity-channel runs share one compiled superstep
+        codec = channel.codec if lossy else None
+        ef_flag = channel.error_feedback if lossy else True
+        consts = strategy.traced_state(state)
+        if plan is not None:
+            # the static adversary row rides as a traced const input (§3g)
+            consts = (consts, jnp.asarray(plan.byz_row()))
+        round_fn = _build_traced_round(strategy, sampler, codec, ef_flag,
+                                       placement, update_fn, fault_plan=plan,
+                                       defense=defense, min_quorum=min_quorum)
+        cache = _superstep_cache(
+            placement, strategy, sampler, codec, ef_flag, update_fn, acc_fn,
+            fault_cfg=None if plan is None else plan.cfg,
+            robust_spec=robust_spec, min_quorum=min_quorum)
+        eval_fn = lambda st, ed: placement.eval_traced(acc_fn, st, ed[0],
+                                                       ed[1])
+        cost = strategy.comm(state)     # round-constant by the traceability
+        history = History()             # contract (state never changes)
+        assignment = strategy.membership(state)      # round-constant too
+        ul_bits_pc = per_client_uplink_bits(channel, ctx, payload, m)
+        t_accum = 0.0
+        carry = (key, stacked, opt_state, ef if lossy else None)
 
     for rnd, nxt in _eval_rounds(fl.rounds, fl.eval_every):
         length = nxt - rnd + 1
-        carry, outs, accs = placement.run_supersteps(
-            round_fn, carry, data, consts, length, cache=cache,
-            eval_fn=eval_fn, eval_data=(fed.x_val, fed.y_val))
+        with jax.profiler.TraceAnnotation("fl.superstep"):
+            carry, outs, accs = placement.run_supersteps(
+                round_fn, carry, data, consts, length, cache=cache,
+                eval_fn=eval_fn, eval_data=(fed.x_val, fed.y_val))
         masks, crashes, qs = outs
-        # the chunk's ONE blocking device->host transfer — and only when a
-        # clock, the bits axis or a meter actually consumes the masks
-        masks_np = (np.asarray(masks)
-                    if masks is not None
-                    and (channel is not None or system is not None
-                         or meter is not None or fmeter is not None)
-                    else None)
-        crashes_np = None if crashes is None else np.asarray(crashes)
-        qs_np = None if qs is None else np.asarray(qs)
-        for i in range(length):
-            mrow = None if masks_np is None else masks_np[i]
-            crow = None if crashes_np is None else crashes_np[i]
-            eff = mrow
-            if crow is not None:
-                eff = ~crow if eff is None else eff & ~crow
-            n_eff = m if eff is None else int(eff.sum())
-            ok = min_quorum is None or n_eff >= min_quorum
-            # a quorum-skipped round moves no server model: no downlink
-            # streams, no membership-aware broadcast — but the clients DID
-            # compute and upload (eff mask → compute + uplink time accrue)
-            t_accum = charge_round(
-                history, cost if ok else CommCost(0, 0), eff, m, payload,
-                link, system, channel, t_accum,
-                assignment if ok else None, ul_bits_pc, meter)
-            if fmeter is not None:
-                qrow = None if qs_np is None else qs_np[i]
-                rbits = qbits = 0
-                if channel is not None:
-                    rbits = (n_eff * payload if ul_bits_pc is None else
-                             int(np.sum(ul_bits_pc[eff]) if eff is not None
-                                 else np.sum(ul_bits_pc)))
-                    if qrow is not None:
-                        qbits = int(np.sum(qrow <= 0)) * payload
-                fmeter.charge(crow, qrow, ok, rbits, qbits)
-        mean_acc, worst_acc = reduce_scores(accs)
-        record_eval(history, nxt, mean_acc, worst_acc, t_accum)
+        with jax.profiler.TraceAnnotation("fl.readback"):
+            # the chunk's blocking device->host reads: the masks only when
+            # a clock, the bits axis or a meter consumes them, the scores
+            masks_np = (np.asarray(masks)
+                        if masks is not None
+                        and (channel is not None or system is not None
+                             or meter is not None or fmeter is not None)
+                        else None)
+            crashes_np = None if crashes is None else np.asarray(crashes)
+            qs_np = None if qs is None else np.asarray(qs)
+            mean_acc, worst_acc = reduce_scores(accs)
+        with jax.profiler.TraceAnnotation("fl.replay"):
+            for i in range(length):
+                mrow = None if masks_np is None else masks_np[i]
+                crow = None if crashes_np is None else crashes_np[i]
+                eff = mrow
+                if crow is not None:
+                    eff = ~crow if eff is None else eff & ~crow
+                n_eff = m if eff is None else int(eff.sum())
+                ok = min_quorum is None or n_eff >= min_quorum
+                # a quorum-skipped round moves no server model: no downlink
+                # streams, no membership-aware broadcast — but the clients
+                # DID compute and upload (eff mask → compute + uplink time
+                # accrue)
+                t_accum = charge_round(
+                    history, cost if ok else CommCost(0, 0), eff, m,
+                    payload, link, system, channel, t_accum,
+                    assignment if ok else None, ul_bits_pc, meter)
+                if fmeter is not None:
+                    qrow = None if qs_np is None else qs_np[i]
+                    rbits = qbits = 0
+                    if channel is not None:
+                        rbits = (n_eff * payload if ul_bits_pc is None
+                                 else int(np.sum(ul_bits_pc[eff])
+                                          if eff is not None
+                                          else np.sum(ul_bits_pc)))
+                        if qrow is not None:
+                            qbits = int(np.sum(qrow <= 0)) * payload
+                    fmeter.charge(crow, qrow, ok, rbits, qbits)
+            record_eval(history, nxt, mean_acc, worst_acc, t_accum)
 
-    _, stacked, opt_state, _ = carry
-    history = finalize_history(history, strategy, state, keep_state,
-                               stacked, opt_state)
-    if meter is not None:
-        history.extra["hierarchy"] = meter.extra()
-    if fmeter is not None:
-        history.extra["faults"] = fmeter.extra()
-    if channel is not None:
-        channel_extra(history, channel, link, model_bits, payload)
+    with jax.profiler.TraceAnnotation("fl.finalize"):
+        _, stacked, opt_state, _ = carry
+        history = finalize_history(history, strategy, state, keep_state,
+                                   stacked, opt_state)
+        if meter is not None:
+            history.extra["hierarchy"] = meter.extra()
+        if fmeter is not None:
+            history.extra["faults"] = fmeter.extra()
+        if channel is not None:
+            channel_extra(history, channel, link, model_bits, payload)
     return history
 
 
