@@ -1,7 +1,18 @@
 """LeNet-5 CNN — the paper's model for EMNIST/CIFAR experiments [LeCun 1998].
 
-Functional raw-JAX implementation (lax.conv).  Supports 28x28x1 (EMNIST) and
-32x32x3 (CIFAR) inputs via config.
+Functional raw-JAX implementation.  Supports 28x28x1 (EMNIST) and 32x32x3
+(CIFAR) inputs via config.
+
+The two convolutions are contractions, not `lax.conv`.  The engine runs the
+local update and the eval as a `vmap` over m clients, each with its own
+weights, and JAX's batching rule turns a `lax.conv` with batched weights
+into one convolution with `feature_group_count = m` (conv1: one input
+channel per group), the worst case for the TPU's matrix unit.  `_conv`
+instead takes shifted windows of the input, which moves data and rounds
+nothing, and contracts them with the weight, one `einsum` per kernel row,
+at the default matmul precision, as the convolution ran.  Under the client
+`vmap` the forward pass and both gradients are then dots with the client
+as a batch dimension (DESIGN.md §3).
 """
 from __future__ import annotations
 
@@ -56,10 +67,23 @@ def init_params(key, cfg: LeNetConfig) -> Dict[str, jnp.ndarray]:
 
 
 def _conv(x, w, b):
-    y = jax.lax.conv_general_dilated(
-        x, w, window_strides=(1, 1), padding="VALID",
-        dimension_numbers=("NHWC", "OIHW", "NHWC"))
-    return y + b[None, None, None, :]
+    """Valid stride-1 k x k convolution, (B, H, W, C) x (O, C, k, k) ->
+    (B, H-k+1, W-k+1, O), as contractions (module docstring).  The image
+    is flattened to (B, C, H*W), so a tap is a shift of the flat axis.
+    The k column shifts are stacked once; kernel row i contracts their
+    (C, k) axes, shifted by i rows, with ``w[:, :, i, :]``, and the k
+    row terms are summed.  Each output row is computed at all W columns
+    and the k-1 that wrap round into the next row are dropped."""
+    o, c, k, _ = w.shape
+    bsz, h, wd, _ = x.shape
+    ho, wo, n = h - k + 1, wd - k + 1, (h - k + 1) * wd
+    flat = jnp.pad(x.transpose(0, 3, 1, 2).reshape(bsz, c, h * wd),
+                   ((0, 0), (0, 0), (0, k - 1)))
+    cols = jnp.stack([flat[:, :, j:j + n + (k - 1) * wd] for j in range(k)],
+                     axis=2)
+    y = sum(jnp.einsum("bcjn,ocj->bon", cols[..., i * wd:i * wd + n],
+                       w[:, :, i, :]) for i in range(k))
+    return y.reshape(bsz, o, ho, wd)[:, :, :, :wo].transpose(0, 2, 3, 1) + b
 
 
 def _pool(x):
